@@ -44,6 +44,7 @@ import numpy as np
 from repro.core.cost import SystemParams
 from repro.core.pattern import pattern_of
 from repro.edge.system import EdgeCloudSystem
+from repro.kernels import use_compile_cache
 from repro.rdf.generator import generate_watdiv_like, workload_sparql
 from repro.rdf.sharding import ShardedTripleStore
 from repro.runtime.admission import AdmissionError, AdmissionQueue
@@ -130,6 +131,7 @@ def main() -> None:
                     help="write machine-readable results (BENCH_ingest"
                          ".json)")
     args = ap.parse_args()
+    use_compile_cache()
 
     g = generate_watdiv_like(scale=args.scale, seed=0)
     sys_ = build_system(g, args.shards)

@@ -42,6 +42,7 @@ import time
 
 import numpy as np
 
+from repro.kernels import use_compile_cache
 from repro.rdf.generator import generate_watdiv_like, workload_sparql
 from repro.runtime.admission import AdmissionError, AdmissionQueue
 from repro.sparql.endpoint import SparqlEndpoint
@@ -177,6 +178,7 @@ def main() -> None:
                     help="write machine-readable results (BENCH_serving"
                          ".json)")
     args = ap.parse_args()
+    use_compile_cache()
 
     g = generate_watdiv_like(scale=args.scale, seed=0)
     texts = workload_sparql(g, args.unique, seed=1)

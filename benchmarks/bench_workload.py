@@ -35,6 +35,7 @@ from __future__ import annotations
 import argparse
 import json
 
+from repro.kernels import use_compile_cache
 from repro.rdf.generator import generate_watdiv_like
 from repro.runtime.admission import AdmissionQueue
 from repro.sparql.endpoint import SparqlEndpoint
@@ -104,6 +105,7 @@ def main() -> None:
                     help="write machine-readable results "
                          "(BENCH_workload.json)")
     args = ap.parse_args()
+    use_compile_cache()
 
     rows: list[dict] = []
     g = generate_watdiv_like(scale=args.scale, seed=args.seed)

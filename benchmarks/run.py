@@ -13,6 +13,8 @@ import sys
 import time
 import traceback
 
+from repro.kernels import use_compile_cache
+
 
 def main() -> None:
     ap = argparse.ArgumentParser()
@@ -21,6 +23,7 @@ def main() -> None:
     ap.add_argument("--full", action="store_true",
                     help="larger grids (slower)")
     args, _ = ap.parse_known_args()
+    use_compile_cache()
 
     from . import paper_tables, roofline
     selected = [s for s in args.only.split(",") if s]

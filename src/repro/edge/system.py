@@ -113,8 +113,8 @@ def _xla_initialized() -> bool:
     """True once any XLA backend is live in this process — forking then is
     unsafe (XLA's runtime threads can leave locks held in the child).
 
-    Fails CLOSED: if jax is imported but the introspection point moved
-    (private API — ``jax._src.xla_bridge._backends`` in jax 0.4.x), a live
+    Fails CLOSED: if jax is imported but the introspection point
+    (``jax._src.xla_bridge._backends``, a private API) is missing, a live
     runtime can't be ruled out and process-mode overlap is disabled rather
     than risking a fork deadlock.
     """

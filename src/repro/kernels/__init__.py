@@ -5,6 +5,13 @@
 from __future__ import annotations
 
 import functools
+import os
+from pathlib import Path
+
+#: where :func:`use_compile_cache` keeps JAX's persistent compilation cache
+#: when ``JAX_COMPILATION_CACHE_DIR`` is unset: a fixed directory of the
+#: checkout (listed in ``.gitignore``)
+REPO_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
 
 
 @functools.lru_cache(maxsize=1)
@@ -19,3 +26,22 @@ def default_interpret() -> bool:
     import jax
 
     return jax.default_backend() not in ("tpu", "gpu", "cuda", "rocm")
+
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, places the cache: JAX reads it
+    itself and no other directory is set here. Otherwise the cache goes to
+    :data:`REPO_CACHE_DIR`, a fixed path: a temp or per-run directory would
+    start empty every run and never hit. Every compile is kept (the default skips those under a second, which
+    is every kernel of the query path). Call before the first compile.
+    """
+    import jax
+
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = str(REPO_CACHE_DIR)
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return path

@@ -35,31 +35,32 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .triple_scan import (PATTERN_GROUP, Columns, match_rows, pad_columns,
+                          whole_tiles)
+
 _PAD_KEY = jnp.iinfo(jnp.int32).max   # sorted-column padding (ignored via K)
 
 
 def _probe_many_kernel(meta_ref, key_ref, probe_ref, lo_ref, hi_ref, *,
-                       bk: int, bp: int):
+                       bk: int, bp: int, group: int):
     ki = pl.program_id(2)
     n_keys = meta_ref[0]
-    keys = key_ref[...]                                # [bk] int32
-    v = probe_ref[...]                                 # [1, bp] int32
+    kv = key_ref[...][:, None]                         # [bk, 1] int32
     # index-mask the key padding: only positions < n_keys count
     idx = jax.lax.broadcasted_iota(jnp.int32, (bk, bp), 0) + ki * bk
     valid = idx < n_keys                               # [bk, bp]
-    kv = keys[:, None]                                 # [bk, 1]
-    lo_blk = ((kv < v) & valid).astype(jnp.int32).sum(axis=0)    # [bp]
-    hi_blk = ((kv <= v) & valid).astype(jnp.int32).sum(axis=0)   # [bp]
 
     @pl.when(ki == 0)
     def _init():
-        lo_ref[...] = lo_blk[None, :]
-        hi_ref[...] = hi_blk[None, :]
+        lo_ref[...] = jnp.zeros_like(lo_ref)
+        hi_ref[...] = jnp.zeros_like(hi_ref)
 
-    @pl.when(ki > 0)
-    def _acc():
-        lo_ref[...] += lo_blk[None, :]
-        hi_ref[...] += hi_blk[None, :]
+    for j in range(group):
+        v = probe_ref[pl.ds(j, 1), :]                  # [1, bp] int32
+        lo_ref[pl.ds(j, 1), :] += ((kv < v) & valid).astype(
+            jnp.int32).sum(axis=0, keepdims=True)
+        hi_ref[pl.ds(j, 1), :] += ((kv <= v) & valid).astype(
+            jnp.int32).sum(axis=0, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("bk", "bp", "interpret"))
@@ -73,34 +74,39 @@ def probe_sorted_many(keys: jnp.ndarray, probes: jnp.ndarray,
     ``hi[q, j]`` the ``"right"`` bound; ``hi - lo`` is each probe's run
     length.  Key padding is masked by true length (any probe value is
     safe); pad *probes* with ``-1`` to get ``lo == hi == 0`` runs for
-    non-negative id spaces.
+    non-negative id spaces.  Probe rows go through the kernel in groups of
+    up to 8 (one ``(group, bp)`` tile per step), so any Q is legal.
     """
+    whole_tiles(bk=bk)
+    if bp % 128:
+        raise ValueError(f"bp must be a multiple of 128, got {bp}")
     K = keys.shape[0]
     Q, P = probes.shape
     k_pad = max(bk, ((K + bk - 1) // bk) * bk)
     if k_pad != K:
         keys = jnp.pad(keys, (0, k_pad - K), constant_values=_PAD_KEY)
+    group = min(Q, PATTERN_GROUP)
+    q_pad = pl.cdiv(Q, group) * group
     p_pad = ((P + bp - 1) // bp) * bp
-    if p_pad != P:
-        probes = jnp.pad(probes, ((0, 0), (0, p_pad - P)),
+    if (q_pad, p_pad) != (Q, P):
+        probes = jnp.pad(probes, ((0, q_pad - Q), (0, p_pad - P)),
                          constant_values=-1)
     meta = jnp.asarray([K], jnp.int32)
+    row_spec = pl.BlockSpec((group, bp), lambda qi, pi, ki, meta: (qi, pi))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(Q, p_pad // bp, k_pad // bk),
+        grid=(q_pad // group, p_pad // bp, k_pad // bk),
         in_specs=[pl.BlockSpec((bk,), lambda qi, pi, ki, meta: (ki,)),
-                  pl.BlockSpec((1, bp), lambda qi, pi, ki, meta: (qi, pi))],
-        out_specs=[pl.BlockSpec((1, bp), lambda qi, pi, ki, meta: (qi, pi)),
-                   pl.BlockSpec((1, bp), lambda qi, pi, ki, meta: (qi, pi))],
+                  row_spec],
+        out_specs=[row_spec, row_spec],
     )
     lo, hi = pl.pallas_call(
-        functools.partial(_probe_many_kernel, bk=bk, bp=bp),
+        functools.partial(_probe_many_kernel, bk=bk, bp=bp, group=group),
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((Q, p_pad), jnp.int32),
-                   jax.ShapeDtypeStruct((Q, p_pad), jnp.int32)],
+        out_shape=[jax.ShapeDtypeStruct((q_pad, p_pad), jnp.int32)] * 2,
         interpret=interpret,
     )(meta, keys.astype(jnp.int32), probes.astype(jnp.int32))
-    return lo[:, :P], hi[:, :P]
+    return lo[:Q, :P], hi[:Q, :P]
 
 
 def probe_sorted(keys: jnp.ndarray, probes: jnp.ndarray, bk: int = 2048,
@@ -112,15 +118,13 @@ def probe_sorted(keys: jnp.ndarray, probes: jnp.ndarray, bk: int = 2048,
     return lo[0], hi[0]
 
 
-def _scan_probe_kernel(meta_ref, key_ref, trip_ref, mask_ref, lo_ref, hi_ref,
-                       *, bt: int, bk: int):
+def _scan_probe_kernel(meta_ref, key_ref, s_ref, p_ref, o_ref, mask_ref,
+                       lo_ref, hi_ref, *, bt: int, bk: int, col: int):
     ki = pl.program_id(1)
-    s, p, o = meta_ref[0], meta_ref[1], meta_ref[2]
-    col, n_keys = meta_ref[3], meta_ref[4]
-    t = trip_ref[...]                                  # [bt, 3] int32
+    n_keys = meta_ref[3]
     keys = key_ref[...]                                # [bk] int32
 
-    vals = jnp.where(col == 0, t[:, 0], t[:, 2])       # probe column [bt]
+    vals = (s_ref if col == 0 else o_ref)[...]         # probe column [bt]
     idx = jax.lax.broadcasted_iota(jnp.int32, (bt, bk), 1) + ki * bk
     valid = idx < n_keys                               # [bt, bk]
     kv = keys[None, :]                                 # [1, bk]
@@ -129,10 +133,8 @@ def _scan_probe_kernel(meta_ref, key_ref, trip_ref, mask_ref, lo_ref, hi_ref,
 
     @pl.when(ki == 0)
     def _init():
-        m = jnp.ones((bt,), jnp.bool_)
-        m &= (t[:, 0] == s) | (s < 0)
-        m &= (t[:, 1] == p) | (p < 0)
-        m &= (t[:, 2] == o) | (o < 0)
+        m = match_rows(s_ref[...], p_ref[...], o_ref[...],
+                       meta_ref[0], meta_ref[1], meta_ref[2])
         mask_ref[...] = m.astype(jnp.int32)
         lo_ref[...] = lo_blk
         hi_ref[...] = hi_blk
@@ -145,48 +147,44 @@ def _scan_probe_kernel(meta_ref, key_ref, trip_ref, mask_ref, lo_ref, hi_ref,
 
 @functools.partial(jax.jit,
                    static_argnames=("col", "bt", "bk", "interpret"))
-def scan_probe(triples: jnp.ndarray, pattern: jnp.ndarray,
+def scan_probe(cols: Columns, pattern: jnp.ndarray,
                keys: jnp.ndarray, col: int, bt: int = 2048, bk: int = 2048,
                interpret: bool = False) -> tuple[jnp.ndarray, jnp.ndarray,
                                                  jnp.ndarray]:
     """Fused candidate scan + first-join probe in one kernel launch.
 
-    triples [T, 3] int32; pattern [3] int32 (-1 wildcards); keys [K] int32
-    ascending sorted; ``col`` selects the probe column (0 = subject,
-    2 = object).  Returns ``(mask [T], lo [T], hi [T])`` where ``mask`` is
-    the scan match mask and ``lo/hi`` are searchsorted left/right bounds of
-    *every* row's probe-column value (consumers take
-    ``counts = where(mask, hi - lo, 0)``) — the star-shape seed scan and
-    its first equi-join without materializing the matched rows in between.
+    cols (s, p, o) three [T] int32 columns; pattern [3] int32 (-1
+    wildcards); keys [K] int32 ascending sorted; ``col`` selects the probe
+    column (0 = subject, 2 = object).  Returns ``(mask [T], lo [T], hi
+    [T])`` where ``mask`` is the scan match mask and ``lo/hi`` are
+    searchsorted left/right bounds of *every* row's probe-column value
+    (consumers take ``counts = where(mask, hi - lo, 0)``) — the star-shape
+    seed scan and its first equi-join without materializing the matched
+    rows in between.
     """
     if col not in (0, 2):
         raise ValueError(f"col must be 0 (subject) or 2 (object), got {col}")
-    T = triples.shape[0]
+    whole_tiles(bt=bt, bk=bk)
+    T = cols[0].shape[0]
     K = keys.shape[0]
-    t_pad = max(bt, ((T + bt - 1) // bt) * bt)
-    if t_pad != T:
-        triples = jnp.pad(triples, ((0, t_pad - T), (0, 0)),
-                          constant_values=-2)          # never matches
+    t_pad = max(bt, pl.cdiv(T, bt) * bt)
     k_pad = max(bk, ((K + bk - 1) // bk) * bk)
     if k_pad != K:
         keys = jnp.pad(keys, (0, k_pad - K), constant_values=_PAD_KEY)
     meta = jnp.concatenate([pattern.astype(jnp.int32),
-                            jnp.asarray([col, K], jnp.int32)])
+                            jnp.asarray([K], jnp.int32)])
+    col_spec = pl.BlockSpec((bt,), lambda ti, ki, meta: (ti,))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(t_pad // bt, k_pad // bk),
-        in_specs=[pl.BlockSpec((bk,), lambda ti, ki, meta: (ki,)),
-                  pl.BlockSpec((bt, 3), lambda ti, ki, meta: (ti, 0))],
-        out_specs=[pl.BlockSpec((bt,), lambda ti, ki, meta: (ti,)),
-                   pl.BlockSpec((bt,), lambda ti, ki, meta: (ti,)),
-                   pl.BlockSpec((bt,), lambda ti, ki, meta: (ti,))],
+        in_specs=[pl.BlockSpec((bk,), lambda ti, ki, meta: (ki,))]
+        + [col_spec] * 3,
+        out_specs=[col_spec] * 3,
     )
     mask, lo, hi = pl.pallas_call(
-        functools.partial(_scan_probe_kernel, bt=bt, bk=bk),
+        functools.partial(_scan_probe_kernel, bt=bt, bk=bk, col=col),
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((t_pad,), jnp.int32),
-                   jax.ShapeDtypeStruct((t_pad,), jnp.int32),
-                   jax.ShapeDtypeStruct((t_pad,), jnp.int32)],
+        out_shape=[jax.ShapeDtypeStruct((t_pad,), jnp.int32)] * 3,
         interpret=interpret,
-    )(meta, keys.astype(jnp.int32), triples.astype(jnp.int32))
+    )(meta, keys.astype(jnp.int32), *pad_columns(cols, t_pad))
     return mask[:T], lo[:T], hi[:T]

@@ -15,7 +15,6 @@ from .decode_attention import decode_attention as _decode_pallas
 from .embedding_bag import embedding_bag_pallas
 from .flash_attention import flash_attention as _flash_pallas
 from .segment_mp import segment_sum_sorted as _segmp_pallas
-from .triple_scan import triple_scan as _scan_pallas
 
 
 def _backend(impl: str) -> str:
@@ -63,11 +62,3 @@ def embedding_bag(table, ids, mask, *, combiner="mean", impl: str = "auto"):
     return embedding_bag_pallas(table, ids, mask, combiner=combiner,
                                 interpret=(b == "interpret"))
 
-
-def triple_scan(triples, pattern, *, impl: str = "auto"):
-    b = _backend(impl)
-    if b == "xla":
-        return ref.triple_scan_reference(triples, int(pattern[0]),
-                                         int(pattern[1]), int(pattern[2]))
-    return _scan_pallas(triples, jnp.asarray(pattern),
-                        interpret=(b == "interpret"))

@@ -78,18 +78,15 @@ def embedding_bag_reference(table: jnp.ndarray, ids: jnp.ndarray,
     return s / cnt
 
 
-def triple_scan_reference(triples: jnp.ndarray, s: int, p: int,
-                          o: int) -> jnp.ndarray:
-    """triples [T, 3] int32; s/p/o pattern ids, -1 == wildcard.
+def triple_scan_reference(cols, s: int, p: int, o: int) -> jnp.ndarray:
+    """cols (s, p, o) three [T] int32 columns; s/p/o pattern ids, -1 ==
+    wildcard.
 
     Returns int32 match mask [T]."""
-    m = jnp.ones(triples.shape[0], bool)
-    if s >= 0:
-        m &= triples[:, 0] == s
-    if p >= 0:
-        m &= triples[:, 1] == p
-    if o >= 0:
-        m &= triples[:, 2] == o
+    m = jnp.ones(cols[0].shape[0], bool)
+    for col, v in zip(cols, (s, p, o)):
+        if v >= 0:
+            m &= col == v
     return m.astype(jnp.int32)
 
 
@@ -103,12 +100,12 @@ def probe_sorted_reference(keys: jnp.ndarray,
     return lo, hi
 
 
-def scan_probe_reference(triples: jnp.ndarray, s: int, p: int, o: int,
+def scan_probe_reference(cols, s: int, p: int, o: int,
                          keys: jnp.ndarray,
                          col: int) -> tuple[jnp.ndarray, jnp.ndarray,
                                             jnp.ndarray]:
     """Fused scan+probe oracle: scan mask plus searchsorted bounds of
     every row's probe-column value (col 0 = subject, 2 = object)."""
-    mask = triple_scan_reference(triples, s, p, o)
-    lo, hi = probe_sorted_reference(keys, triples[:, col])
+    mask = triple_scan_reference(cols, s, p, o)
+    lo, hi = probe_sorted_reference(keys, cols[col])
     return mask, lo, hi

@@ -5,60 +5,12 @@ from __future__ import annotations
 import jax
 
 
-def mesh_axis_types_kwargs(n_axes: int) -> dict:
-    """Compat shim: ``jax.sharding.AxisType`` only exists in newer JAX.
-
-    Older releases (e.g. 0.4.x) neither expose ``AxisType`` nor accept an
-    ``axis_types=`` argument — there every axis is implicitly Auto, which is
-    exactly what we request on newer JAX, so omitting the kwarg is
-    semantics-preserving. Returns ``{"axis_types": (Auto,) * n_axes}`` when
-    available, else ``{}``; splat into ``jax.make_mesh``.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
-
-
-def make_compat_mesh(shape: tuple, axes: tuple):
-    """``jax.make_mesh`` with Auto axis types on any supported JAX version."""
-    return jax.make_mesh(shape, axes, **mesh_axis_types_kwargs(len(axes)))
-
-
-def compat_shard_map(f, mesh, in_specs, out_specs, **kwargs):
-    """``jax.shard_map`` on new JAX, ``jax.experimental.shard_map`` on old.
-
-    Call sites in this repo only pass (f, mesh, in_specs, out_specs), which
-    both implementations accept with identical semantics.
-    """
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-        # pre-pvary JAX cannot type scan carries that start replicated and
-        # become varying (compat_pvary is the identity there), so its
-        # replication checker must be off; new JAX keeps full checking
-        kwargs.setdefault("check_rep", False)
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs)
-
-
-def compat_pvary(x, axes):
-    """``jax.lax.pvary`` where it exists; identity on older JAX.
-
-    ``pvary`` only adjusts replication-typing metadata (varying-axis sets)
-    introduced alongside explicit sharding; pre-AxisType JAX has no such
-    typing, so the identity is exact there.
-    """
-    pvary = getattr(jax.lax, "pvary", None)
-    if pvary is None:
-        return x
-    return pvary(x, axes)
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips/pod (TPU v5e pod slice); 2 pods when multi_pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_compat_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_mesh_for_devices(devices: list, model_axis: int = 16,

@@ -25,7 +25,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..launch.mesh import compat_pvary, compat_shard_map
 from .common import AxisRules, constrain, dense_init, key_tree
 
 
@@ -87,7 +86,7 @@ def mp_aggregate(msg, dst, n, rules, op: str = "sum"):
             part = jax.ops.segment_sum(msg_b, dst_b, num_segments=n)
             return jax.lax.psum_scatter(part, batch, scatter_dimension=0,
                                         tiled=True)
-        return compat_shard_map(body, mesh=mesh,
+        return jax.shard_map(body, mesh=mesh,
                              in_specs=(P(batch, None), P(batch)),
                              out_specs=P(batch, None))(msg, dst)
 
@@ -105,7 +104,7 @@ def mp_aggregate(msg, dst, n, rules, op: str = "sum"):
                 idx = idx * mesh.shape[ax] + jax.lax.axis_index(ax)
             return jax.lax.dynamic_slice_in_dim(full, idx * (n // nsh),
                                                 n // nsh, axis=0)
-        return compat_shard_map(body, mesh=mesh,
+        return jax.shard_map(body, mesh=mesh,
                              in_specs=(P(batch, None), P(batch)),
                              out_specs=P(batch, None))(m, d)
 
@@ -458,14 +457,15 @@ def _nequip_aggregate_fused(cfg: GNNConfig, lp, h0, h1, h2, src, dst, rbf,
             return (a0, a1, a2), None
 
         zeros = tuple(
-            compat_pvary(jnp.zeros((n // nsh, d), jnp.float32), batch)
+            jax.lax.pcast(jnp.zeros((n // nsh, d), jnp.float32), batch,
+                          to="varying")
             for d in (2 * C, 3 * C * 3, 2 * C * 9))
         (a0, a1, a2), _ = jax.lax.scan(chunk, zeros, jnp.arange(n_chunks))
         return a0, a1, a2
 
     nsp = P(batch, None)
     rspecs = tuple(P(*([None] * leaf.ndim)) for leaf in radial_leaves)
-    a0, a1, a2 = compat_shard_map(
+    a0, a1, a2 = jax.shard_map(
         body, mesh=mesh,
         in_specs=(nsp, P(batch, None, None), P(batch, None, None, None),
                   P(batch), P(batch), nsp, nsp,

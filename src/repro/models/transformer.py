@@ -37,7 +37,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..launch.mesh import compat_shard_map
 from .common import (ACTIVATIONS, AxisRules, constrain, dense_init,
                      embed_init, key_tree, rms_norm, rope, softcap)
 
@@ -405,7 +404,7 @@ def moe_ffn(cfg: LMConfig, lp: dict, x: jnp.ndarray,
         aux = jax.lax.pmean(aux, batch)
         return y, aux
 
-    y, aux = compat_shard_map(
+    y, aux = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(None, None), P(tp, fsdp, None), P(tp, fsdp, None),
                   P(tp, None, fsdp), P(batch, None, None)),

@@ -153,16 +153,16 @@ class DeviceBatch:
             rows = empty
         else:
             flat0, off0 = parts[0]
-            arr0 = be._triples(flat0, min_slots=slots)
+            cols0 = be._columns(flat0, min_slots=slots)
             pat = jnp.asarray(be._pattern_vec(tp0))
             fuse_col = self._fuse_col(q, plan, tp0)
             if fuse_col is not None:
                 col, keys = fuse_col
                 mask, lo_all, hi_all = scan_probe(
-                    arr0, pat, keys, col, bt=be.bt, bk=be.bt,
+                    cols0, pat, keys, col, bt=be.bt, bk=be.bt,
                     interpret=be.interpret)
             else:
-                mask = triple_scan(arr0, pat, bt=be.bt,
+                mask = triple_scan(cols0, pat, bt=be.bt,
                                    interpret=be.interpret)
             R = be._scalar(mask.sum())
             if R:
@@ -173,7 +173,7 @@ class DeviceBatch:
                 rows = empty
         for varname, c in ((svar0, 0), (ovar0, 2), (pvar0, 1)):
             if varname is not None:
-                cols[varname] = (arr0[rows, c] if R else empty)
+                cols[varname] = (cols0[c][rows] if R else empty)
         edges[plan[0].pattern] = (rows.astype(jnp.int32), off0)
 
         # ---- presorted probe joins -----------------------------------------
@@ -224,12 +224,12 @@ class DeviceBatch:
                       - jnp.repeat(cum - counts, counts,
                                    total_repeat_length=total))
             sel_local = stids[starts + within]
-            arrk = be._triples(flatk, min_slots=slots)
+            colsk = be._columns(flatk, min_slots=slots)
             for v in cols:
                 cols[v] = cols[v][row_idx]
             for k in edges:
                 edges[k] = (edges[k][0][row_idx], edges[k][1])
-            cols[newvar] = arrk[sel_local, 2 if join_on_s else 0]
+            cols[newvar] = colsk[2 if join_on_s else 0][sel_local]
             edges[step.pattern] = (sel_local.astype(jnp.int32), offk)
             R = total
         return cols, edges

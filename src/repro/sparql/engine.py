@@ -253,12 +253,14 @@ class JaxBackend(MatcherBackend):
     """Scans via the ``triple_scan`` Pallas kernel, joins optionally
     device-resident via the ``probe_sorted`` / ``scan_probe`` kernels.
 
-    [T, 3] triple arrays are staged to the device once per (shard) store
-    version; every scan then evaluates a constant/wildcard mask on-device
+    Each (shard) store is staged to the device once per version as three
+    int32 columns (s, p, o); every scan then evaluates a constant/wildcard
+    mask on-device
     (VPU on TPU, interpret mode on CPU — resolved by
     :func:`repro.kernels.default_interpret` unless ``interpret`` is forced)
     followed by compaction and repeated-variable filters. ``bt`` is the
-    stream block size.
+    stream block size, a multiple of 1024 (the TPU tile of a 1-D int32
+    array).
 
     On a :class:`~repro.rdf.sharding.ShardedTripleStore` each shard is staged
     as its own device array, and a scan streams only the shards it can touch:
@@ -282,10 +284,10 @@ class JaxBackend(MatcherBackend):
 
     name = "jax"
 
-    # device copies of (shard) triple arrays kept alive at once: one engine
+    # device copies of (shard) triple columns kept alive at once: one engine
     # serves cloud + K edge stores interleaved — and a sharded store stages
-    # one array per shard — so a single slot would re-upload [T, 3] arrays
-    # on every store switch within a round
+    # one column set per shard — so a single slot would re-upload the
+    # columns on every store switch within a round
     MAX_STAGED_STORES = 16
     # staged (shard version, predicate) sorted-view tuples for the device
     # join path; four small int32 arrays per hot predicate
@@ -295,7 +297,9 @@ class JaxBackend(MatcherBackend):
                  max_staged: int | None = None,
                  device_resident: bool = True) -> None:
         from ..kernels import default_interpret
+        from ..kernels.triple_scan import whole_tiles
 
+        whole_tiles(bt=bt)
         self.bt = int(bt)
         if interpret is None:
             interpret = default_interpret()
@@ -336,31 +340,54 @@ class JaxBackend(MatcherBackend):
             self.scalar_syncs += 1
         return int(x)
 
-    def _triples(self, store, min_slots: int = 1):
-        """Device [T, 3] int32 copy of one *flat* store (a shard or a
+    def _columns(self, store, min_slots: int = 1):
+        """Device (s, p, o) int32 columns of one *flat* store (a shard or a
         monolithic :class:`TripleStore`), LRU-kept by store version.
 
+        Each column is padded on the host to a multiple of ``bt`` with
+        never-matching rows (predicate -1, see
+        :func:`repro.kernels.triple_scan.match_rows`), so every scan streams
+        the staged arrays as they are; masks come back at the padded length
+        with zeros past ``num_triples``.
+
         ``min_slots`` widens the eviction limit to the number of flat
-        arrays the *current* store needs at once, so a sharded store with
+        stores the *current* store needs at once, so a sharded store with
         more shards than ``max_staged`` never evicts its own shards
         mid-round (which would re-upload the full store every scan).
         """
-        import jax.numpy as jnp
+        import jax
 
         with self._stage_lock:
-            arr = self._staged.get(store.version)
-            if arr is not None:
+            cols = self._staged.get(store.version)
+            if cols is not None:
                 self._staged.move_to_end(store.version)
-                return arr
+                return cols
         if max(store.num_entities, store.num_predicates) >= 2 ** 31:
             raise ValueError("dictionary ids exceed int32 kernel range")
-        arr = jnp.asarray(store.triples(), dtype=jnp.int32)
+        T = store.num_triples
+        t_pad = max(self.bt, -(-T // self.bt) * self.bt)
+        host = np.full((3, t_pad), -1, dtype=np.int32)
+        for i, c in enumerate((store.s, store.p, store.o)):
+            host[i, :T] = c
+        cols = tuple(jax.device_put(host[i]) for i in range(3))
         with self._stage_lock:
-            self._staged[store.version] = arr
+            self._staged[store.version] = cols
             limit = max(self.max_staged, min_slots)
             while len(self._staged) > limit:
                 self._staged.popitem(last=False)
-        return arr
+        return cols
+
+    def stage(self, store: RDFStore) -> int:
+        """Upload every non-empty flat part of ``store`` now instead of at
+        its first scan; returns the device bytes its columns take."""
+        import jax
+
+        parts = getattr(store, "shards", None) or [store]
+        slots = self._store_slots(store)
+        cols = [self._columns(flat, min_slots=slots)
+                for flat in parts if flat.num_triples]
+        jax.block_until_ready(cols)
+        return sum(int(c.nbytes) for part in cols for c in part)
 
     def _pred_views(self, store: RDFStore, pid: int):
         """Device copies of predicate ``pid``'s shard-LOCAL ``PredIndex``
@@ -434,7 +461,7 @@ class JaxBackend(MatcherBackend):
         pat = jnp.asarray(self._pattern_vec(tp))
         slots = self._store_slots(store)
         scan_parts = self._scan_parts(store, tp)
-        masks = [triple_scan(self._triples(flat, min_slots=slots), pat,
+        masks = [triple_scan(self._columns(flat, min_slots=slots), pat,
                              bt=self.bt, interpret=self.interpret)
                  for flat, _off in scan_parts]
         parts: list[np.ndarray] = []
@@ -475,7 +502,7 @@ class JaxBackend(MatcherBackend):
         for flat, off, keys in groups.values():     # one launch per group
             pats = np.stack([self._pattern_vec(uniq[k]) for k in keys])
             launches.append((off, keys, triple_scan_many(
-                self._triples(flat, min_slots=slots), jnp.asarray(pats),
+                self._columns(flat, min_slots=slots), jnp.asarray(pats),
                 bt=self.bt, interpret=self.interpret)))
         # ONE bulk transfer materializes every group's masks together
         fetched = self._fetch([m for _, _, m in launches]) if launches else []

@@ -81,7 +81,7 @@ def test_device_results_equal_host(sharded):
     mono, sh = _stores()
     store = sh if sharded else mono
     qs = _qs(DEVICE_SHAPES + HOST_SHAPES)
-    eng_dev = QueryEngine(backend=JaxBackend(bt=512))
+    eng_dev = QueryEngine(backend=JaxBackend(bt=1024))
     eng_ref = QueryEngine(backend="numpy")
     for q, res, ref in zip(qs, eng_dev.execute_batch(store, qs),
                            eng_ref.execute_batch(store, qs)):
@@ -98,7 +98,7 @@ def test_single_bulk_transfer_per_batch():
     device-eligible costs exactly ONE device->host transfer."""
     _, sh = _stores()
     qs = _qs(DEVICE_SHAPES)
-    bk = JaxBackend(bt=512)
+    bk = JaxBackend(bt=1024)
     eng = QueryEngine(backend=bk)
     before = bk.host_transfers
     eng.execute_batch(sh, qs)
@@ -126,7 +126,7 @@ def test_single_bulk_transfer_per_batch():
 def test_device_resident_off_falls_back():
     _, sh = _stores()
     qs = _qs(DEVICE_SHAPES)
-    eng = QueryEngine(backend=JaxBackend(bt=512, device_resident=False))
+    eng = QueryEngine(backend=JaxBackend(bt=1024, device_resident=False))
     ref = QueryEngine(backend="numpy")
     for res, want in zip(eng.execute_batch(sh, qs),
                          ref.execute_batch(sh, qs)):
@@ -157,10 +157,10 @@ def test_capacity_error_parity():
                     TriplePattern("?y", 1, "?z")], [])
     want = match_bgp(store, q).num_matches
     assert want == n * n
-    ok = QueryEngine(backend=JaxBackend(bt=512), max_rows=want)
+    ok = QueryEngine(backend=JaxBackend(bt=1024), max_rows=want)
     assert ok.execute(store, q).num_matches == want
     assert ok.stats.device_queries == 1
-    tight = QueryEngine(backend=JaxBackend(bt=512), max_rows=want - 1)
+    tight = QueryEngine(backend=JaxBackend(bt=1024), max_rows=want - 1)
     with pytest.raises(MatchCapacityError):
         tight.execute(store, q)
 
@@ -175,7 +175,7 @@ def test_delta_invalidates_staged_views():
     mono = TripleStore(s, p, o, 20, 4)
     q = QueryGraph([TriplePattern("?x", 0, "?y"),
                     TriplePattern("?y", 1, "?z")], [])
-    bk = JaxBackend(bt=512)
+    bk = JaxBackend(bt=1024)
     eng = QueryEngine(backend=bk)
     assert sol_rows(eng.execute(sh, q)) == sol_rows(match_bgp(mono, q))
     staged_before = len(bk._staged_views)
@@ -191,7 +191,7 @@ def test_delta_invalidates_staged_views():
 
 def test_staged_view_lru_bounded():
     _, sh = _stores(scale=0.3, seed=7, shards=2)
-    bk = JaxBackend(bt=512)
+    bk = JaxBackend(bt=1024)
     bk.max_staged_views = 2
     eng = QueryEngine(backend=bk)
     qs = [QueryGraph([TriplePattern("?x", pid, "?y"),

@@ -9,9 +9,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import AxisType
 
 from repro.data.graphs import molecule_batch
-from repro.launch.mesh import make_compat_mesh
 from repro.models.common import AxisRules
 from repro.models.gnn import GNNConfig, gnn_init, gnn_loss, mp_aggregate
 from repro.models.transformer import LMConfig, init_lm_params, lm_loss
@@ -19,7 +19,8 @@ from repro.models.transformer import LMConfig, init_lm_params, lm_loss
 
 @pytest.fixture(scope="module")
 def mesh11():
-    return make_compat_mesh((1, 1), ("data", "model"))
+    return jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def test_moe_shardmap_matches_local(mesh11):

@@ -230,20 +230,21 @@ def test_triple_scan_many_matches_single():
     rng = np.random.default_rng(13)
     tr = rng.integers(0, 30, (1000, 3)).astype(np.int32)
     import jax.numpy as jnp
+    cols = tuple(jnp.asarray(tr[:, c]) for c in range(3))
     pats = np.array([[-1, 1, -1], [4, -1, -1], [-1, -1, -1], [2, 1, 9]],
                     np.int32)
-    many = np.asarray(triple_scan_many(jnp.asarray(tr), jnp.asarray(pats),
-                                       bt=256, interpret=True))
+    many = np.asarray(triple_scan_many(cols, jnp.asarray(pats),
+                                       bt=1024, interpret=True))
     for i in range(len(pats)):
-        one = np.asarray(triple_scan(jnp.asarray(tr), jnp.asarray(pats[i]),
-                                     bt=256, interpret=True))
+        one = np.asarray(triple_scan(cols, jnp.asarray(pats[i]),
+                                     bt=1024, interpret=True))
         assert np.array_equal(many[i], one)
 
 
 def test_jax_backend_prescan_equals_numpy_candidates():
     rng = np.random.default_rng(17)
     store = random_store(rng, n_trip=200)
-    jb = JaxBackend(bt=64)
+    jb = JaxBackend(bt=1024)
     nb = get_backend("numpy")
     tps = [tp for pats in ADVERSARIAL for tp in pats]
     pre = jb.prescan(store, tps)

@@ -497,8 +497,8 @@ def test_device_vs_host_joinstats_parity():
           QueryGraph([TriplePattern("?x", 2, "?y"),
                       TriplePattern("?x", 3, "?z")], []),
           QueryGraph([TriplePattern("?a", 1, "?b")], [])]
-    eng_dev = QueryEngine(backend=JaxBackend(bt=512))
-    eng_host = QueryEngine(backend=JaxBackend(bt=512,
+    eng_dev = QueryEngine(backend=JaxBackend(bt=1024))
+    eng_host = QueryEngine(backend=JaxBackend(bt=1024,
                                               device_resident=False))
     for res, ref in zip(eng_dev.execute_batch(sh, qs),
                         eng_host.execute_batch(sh, qs)):

@@ -12,7 +12,7 @@ from repro.kernels.flash_attention import flash_attention
 from repro.kernels.join_probe import (probe_sorted, probe_sorted_many,
                                       scan_probe)
 from repro.kernels.segment_mp import segment_sum_sorted
-from repro.kernels.triple_scan import triple_scan
+from repro.kernels.triple_scan import triple_scan, triple_scan_many
 
 KEY = jax.random.PRNGKey(0)
 
@@ -139,25 +139,74 @@ def test_embedding_bag_vs_ref(B, F, NNZ, V, D, combiner):
 
 # -- triple scan -------------------------------------------------------------------
 
+def _cols(triples):
+    """[T, 3] rows -> the kernels' (s, p, o) int32 column layout."""
+    return tuple(jnp.asarray(np.asarray(triples)[:, c], jnp.int32)
+                 for c in range(3))
+
+
+SCAN_PATTERNS = [(-1, 3, -1), (7, -1, -1), (-1, -1, -1), (1, 2, 3),
+                 (-1, 4, 9)]
+
+
 @pytest.mark.parametrize("T", [100, 2048, 5000])
 def test_triple_scan_vs_ref(T):
     rng = np.random.default_rng(0)
-    triples = jnp.asarray(rng.integers(0, 50, (T, 3)), jnp.int32)
-    for (s, p, o) in [(-1, 3, -1), (7, -1, -1), (-1, -1, -1), (1, 2, 3),
-                      (-1, 4, 9)]:
-        out = triple_scan(triples, jnp.asarray([s, p, o]), bt=512,
+    cols = _cols(rng.integers(0, 50, (T, 3)))
+    for (s, p, o) in SCAN_PATTERNS:
+        out = triple_scan(cols, jnp.asarray([s, p, o]), bt=1024,
                           interpret=True)
-        want = ref.triple_scan_reference(triples, s, p, o)
+        want = ref.triple_scan_reference(cols, s, p, o)
         np.testing.assert_array_equal(np.asarray(out), np.asarray(want))
+
+
+@pytest.mark.parametrize("Q", [1, 5, 8, 13])
+def test_triple_scan_many_vs_ref(Q):
+    """Any pattern count is legal: Q <= 8 is one tile of Q rows, larger Q
+    pads to whole groups of 8 whose extra rows are sliced away."""
+    rng = np.random.default_rng(Q)
+    cols = _cols(rng.integers(0, 20, (3000, 3)))
+    pats = rng.integers(-1, 20, (Q, 3)).astype(np.int32)
+    pats[0] = (-1, -1, -1)
+    out = np.asarray(triple_scan_many(cols, jnp.asarray(pats), bt=1024,
+                                      interpret=True))
+    assert out.shape == (Q, 3000)
+    for q in range(Q):
+        np.testing.assert_array_equal(
+            out[q], np.asarray(ref.triple_scan_reference(cols, *pats[q])))
+
+
+def test_padding_rows_never_match():
+    """Rows with a negative predicate are staging padding: no pattern,
+    not even the all-wildcard one, matches them."""
+    cols = _cols(np.asarray([[1, 0, 2], [3, 1, 4], [-1, -1, -1]]))
+    for pat in [(-1, -1, -1), (-1, 0, -1), (1, -1, -1)]:
+        mask = np.asarray(triple_scan(cols, jnp.asarray(pat),
+                                      interpret=True))
+        assert mask[2] == 0
+        assert mask[:2].tolist() == np.asarray(
+            ref.triple_scan_reference(cols, *pat))[:2].tolist()
+
+
+def test_scan_blocks_must_be_whole_tiles():
+    """A 1-D int32 block must be a whole number of 1024-element tiles —
+    the TPU compiler refuses anything else, so the wrappers refuse it
+    first, in every mode."""
+    cols = _cols(np.zeros((8, 3), np.int64))
+    with pytest.raises(ValueError):
+        triple_scan(cols, jnp.asarray([-1, -1, -1]), bt=512, interpret=True)
+    with pytest.raises(ValueError):
+        probe_sorted(jnp.zeros(4, jnp.int32), jnp.zeros(4, jnp.int32),
+                     bk=512, interpret=True)
 
 
 def test_triple_scan_agrees_with_matcher_candidates():
     """The kernel implements the matcher's candidate scan semantics."""
     from repro.rdf.generator import generate_watdiv_like
     g = generate_watdiv_like(scale=0.3, seed=5)
-    triples = jnp.asarray(g.store.triples(), jnp.int32)
+    cols = _cols(g.store.triples())
     pid = 3
-    mask = triple_scan(triples, jnp.asarray([-1, pid, -1]), interpret=True)
+    mask = triple_scan(cols, jnp.asarray([-1, pid, -1]), interpret=True)
     got = np.flatnonzero(np.asarray(mask))
     want = np.sort(g.store.pred_tids(pid))
     np.testing.assert_array_equal(got, want)
@@ -179,7 +228,7 @@ def test_probe_sorted_vs_searchsorted(K, P):
     keys = np.sort(rng.integers(0, 60, K)).astype(np.int32)
     probes = rng.integers(-10, 90, P).astype(np.int32)
     lo, hi = probe_sorted(jnp.asarray(keys), jnp.asarray(probes),
-                          bk=512, bp=128, interpret=True)
+                          bk=1024, bp=128, interpret=True)
     np.testing.assert_array_equal(
         np.asarray(lo), np.searchsorted(keys, probes, side="left"))
     np.testing.assert_array_equal(
@@ -193,10 +242,10 @@ def test_probe_sorted_vs_searchsorted(K, P):
 
 def test_probe_sorted_many_vs_searchsorted():
     rng = np.random.default_rng(7)
-    keys = np.sort(rng.integers(0, 500, 777)).astype(np.int32)
+    keys = np.sort(rng.integers(0, 500, 2777)).astype(np.int32)
     probes = rng.integers(-5, 600, (5, 300)).astype(np.int32)
     lo, hi = probe_sorted_many(jnp.asarray(keys), jnp.asarray(probes),
-                               bk=256, bp=128, interpret=True)
+                               bk=1024, bp=128, interpret=True)
     for q in range(probes.shape[0]):
         np.testing.assert_array_equal(
             np.asarray(lo[q]), np.searchsorted(keys, probes[q], side="left"))
@@ -204,20 +253,20 @@ def test_probe_sorted_many_vs_searchsorted():
             np.asarray(hi[q]), np.searchsorted(keys, probes[q], side="right"))
 
 
-@pytest.mark.parametrize("T,K,bt", [(100, 50, 512), (2500, 0, 512),
+@pytest.mark.parametrize("T,K,bt", [(100, 50, 1024), (2500, 0, 1024),
                                     (2048, 2048, 1024), (33, 5, 2048)])
 def test_scan_probe_fused_vs_ref(T, K, bt):
     """Fused scan+first-join kernel vs the unfused oracle: empty key
     columns, chunk-boundary block sizes, all-wildcard patterns, both
     probe columns."""
     rng = np.random.default_rng(T + K)
-    triples = jnp.asarray(rng.integers(0, 60, (T, 3)), jnp.int32)
+    cols = _cols(rng.integers(0, 60, (T, 3)))
     keys = jnp.asarray(np.sort(rng.integers(0, 60, K)), jnp.int32)
     for pat in [(-1, 3, -1), (-1, -1, -1), (7, 2, -1), (1, 2, 3)]:
         for col in (0, 2):
-            m, lo, hi = scan_probe(triples, jnp.asarray(pat, jnp.int32),
+            m, lo, hi = scan_probe(cols, jnp.asarray(pat, jnp.int32),
                                    keys, col, bt=bt, bk=bt, interpret=True)
-            wm, wlo, whi = ref.scan_probe_reference(triples, *pat, keys, col)
+            wm, wlo, whi = ref.scan_probe_reference(cols, *pat, keys, col)
             np.testing.assert_array_equal(np.asarray(m), np.asarray(wm))
             np.testing.assert_array_equal(np.asarray(lo), np.asarray(wlo))
             np.testing.assert_array_equal(np.asarray(hi), np.asarray(whi))
@@ -225,7 +274,7 @@ def test_scan_probe_fused_vs_ref(T, K, bt):
 
 def test_scan_probe_rejects_predicate_column():
     with pytest.raises(ValueError):
-        scan_probe(jnp.zeros((8, 3), jnp.int32),
+        scan_probe(_cols(np.zeros((8, 3), np.int64)),
                    jnp.asarray([-1, -1, -1], jnp.int32),
                    jnp.zeros(4, jnp.int32), col=1, interpret=True)
 
@@ -246,8 +295,37 @@ def test_probe_sorted_compiled_matches_interpret():
 @pytest.mark.requires_accelerator
 def test_triple_scan_compiled_matches_interpret():
     rng = np.random.default_rng(1)
-    triples = jnp.asarray(rng.integers(0, 50, (4096, 3)), jnp.int32)
+    cols = _cols(rng.integers(0, 50, (4096, 3)))
     pat = jnp.asarray([-1, 3, -1])
     np.testing.assert_array_equal(
-        np.asarray(triple_scan(triples, pat, interpret=True)),
-        np.asarray(triple_scan(triples, pat, interpret=False)))
+        np.asarray(triple_scan(cols, pat, interpret=True)),
+        np.asarray(triple_scan(cols, pat, interpret=False)))
+
+
+# -- persistent compilation cache -------------------------------------------------
+
+@pytest.mark.parametrize("placed", [False, True])
+def test_compile_cache_placement(monkeypatch, tmp_path, placed):
+    """``JAX_COMPILATION_CACHE_DIR`` places the cache and nothing else is
+    set; without it the cache goes to the checkout's fixed directory."""
+    from repro.kernels import REPO_CACHE_DIR, use_compile_cache
+
+    saved = {k: getattr(jax.config, k) for k in (
+        "jax_compilation_cache_dir",
+        "jax_persistent_cache_min_compile_time_secs")}
+    if placed:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    try:
+        got = use_compile_cache()
+        now = jax.config.jax_compilation_cache_dir
+    finally:
+        for k, v in saved.items():
+            jax.config.update(k, v)
+    if placed:
+        assert got == str(tmp_path)
+        assert now == saved["jax_compilation_cache_dir"]
+    else:
+        assert got == now == str(REPO_CACHE_DIR)
+        assert (REPO_CACHE_DIR.parent / "chip_smoke.py").is_file()

@@ -2,10 +2,7 @@
 
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # declared test dep; deterministic fallback
-    from _hypothesis_fallback import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.induced import (induced_edge_ids, induced_edge_ids_semijoin,
                                 induced_subgraph, pattern_to_query)

@@ -2,10 +2,7 @@
 
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # declared test dep; deterministic fallback
-    from _hypothesis_fallback import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.rdf.dictionary import Dictionary
 from repro.rdf.generator import generate_watdiv_like, workload_sparql

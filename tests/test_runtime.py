@@ -168,12 +168,12 @@ def test_plan_mesh_elastic():
 def test_elastic_restore_across_meshes(tmp_path):
     """Save under one sharding layout, restore under another (1-device CPU:
     layouts differ logically; correctness = values survive)."""
-    from jax.sharding import NamedSharding, PartitionSpec as P
+    from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
     d = str(tmp_path / "ckpt")
     state = {"w": jnp.arange(16, dtype=jnp.float32).reshape(4, 4)}
     save_checkpoint(d, 1, state)
-    from repro.launch.mesh import make_compat_mesh
-    mesh = make_compat_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     sh = {"w": NamedSharding(mesh, P("data", None))}
     step, restored = restore_checkpoint(d, state, shardings=sh)
     assert np.allclose(restored["w"], np.asarray(state["w"]))

@@ -124,7 +124,7 @@ def test_jax_staging_lru_scales_to_shard_count():
     from repro.sparql.engine import JaxBackend
     rng = np.random.default_rng(6)
     mono, sh = paired_stores(rng, 6, n_pred=6, n_trip=120)
-    jb = JaxBackend(bt=64, max_staged=2)     # fewer slots than shards
+    jb = JaxBackend(bt=1024, max_staged=2)     # fewer slots than shards
     queries = [QueryGraph(pats, []) for pats in ADVERSARIAL]
     eng = QueryEngine(backend=jb)
     refs = eng.execute_batch(mono, queries)
