@@ -68,6 +68,7 @@ from ..sparql.engine import QueryEngine
 from ..sparql.matcher import MatchResult
 from ..sparql.partial_eval import execute_partial_batch, plan_partial
 from ..sparql.query import QueryGraph, parse_query
+from ..tracing import span
 from .rebalance import RebalanceHandle, RebalanceManager, RebalanceReport
 from .server import CloudServer, EdgeServer, ExecutionRecord
 
@@ -523,8 +524,9 @@ class EdgeCloudSystem:
             # proven in ms; at fleet scale the incumbent is returned
             sched_kw.setdefault("max_seconds", 2.0)
         t0 = time.perf_counter()
-        sr: ScheduleResult = schedule(tasks, params_batch, policy=policy,
-                                      **sched_kw)
+        with span("scheduler.schedule"):
+            sr: ScheduleResult = schedule(tasks, params_batch,
+                                          policy=policy, **sched_kw)
         return tasks, params_batch, sr, time.perf_counter() - t0
 
     def _observe_pattern(self, user: int, q) -> None:

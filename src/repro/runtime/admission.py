@@ -43,6 +43,8 @@ import threading
 import time
 from dataclasses import dataclass, field
 
+from ..tracing import span
+
 
 class AdmissionError(Exception):
     """Base class for admission-layer failures."""
@@ -122,6 +124,8 @@ class BatchStats:
     engine_cache_hits: int        # engine result-cache hits (delta)
     scans_deduped: int            # engine scan dedups (delta)
     write_commits: int = 0        # store commits this window's writes took
+    scan_fetch_bytes: int = 0     # engine scan-mask bytes fetched (delta)
+    scan_rows_kept: int = 0       # engine candidate rows kept (delta)
     # scheduler provenance (mode="round"/"pool" only): per-assignment
     # counts (-1 cloud, -2 partial, k per edge/replica) and the modeled
     # scheduling objective of the window's read batch
@@ -358,16 +362,17 @@ class AdmissionQueue:
             # a full window keeps worst-case wait at window_s even under
             # burst arrival
             window_end = self._queue[0].enqueued_at + self.window_s
-            while (len(self._queue) < self.max_batch
-                   and not self._closed):
-                remaining = window_end - time.monotonic()
-                if remaining <= 0:
-                    break
-                self._cond.wait(remaining)
-                if not self._queue:       # spurious wake after a drain
-                    return []
-            batch = self._queue[:self.max_batch]
-            del self._queue[:len(batch)]
+            with span("admission.window"):
+                while (len(self._queue) < self.max_batch
+                       and not self._closed):
+                    remaining = window_end - time.monotonic()
+                    if remaining <= 0:
+                        break
+                    self._cond.wait(remaining)
+                    if not self._queue:   # spurious wake after a drain
+                        return []
+                batch = self._queue[:self.max_batch]
+                del self._queue[:len(batch)]
             self._depth_after_drain = len(self._queue)
         # deadline enforcement AT dispatch: expired tickets never reach
         # the engine (and never pollute a batch's wall clock)
@@ -403,15 +408,21 @@ class AdmissionQueue:
         are captured into :class:`BatchStats` and aggregated into
         :class:`AdmissionStats.assignment_counts`.
         """
+        seq = self._seq
+        self._seq += 1
+        with span("admission.batch", batch=seq, size=len(batch)):
+            self._serve_batch(batch, seq)
+
+    def _serve_batch(self, batch: list[Ticket], seq: int) -> None:
         ep = self.endpoint
         reads = [t for t in batch if not t.is_update]
         updates = [t for t in batch if t.is_update]
         texts = [t.text for t in batch]
-        seq = self._seq
-        self._seq += 1
         memo0 = ep.memo_hits
         hits0 = ep.stats.cache_hits
         dedup0 = ep.stats.scans_deduped
+        fetched0 = ep.stats.scan_fetch_bytes
+        kept0 = ep.stats.scan_rows_kept
         commits0 = ep.write_commits
         assignment_counts: dict | None = None
         objective: float | None = None
@@ -499,6 +510,8 @@ class AdmissionQueue:
             engine_cache_hits=ep.stats.cache_hits - hits0,
             scans_deduped=ep.stats.scans_deduped - dedup0,
             write_commits=ep.write_commits - commits0,
+            scan_fetch_bytes=ep.stats.scan_fetch_bytes - fetched0,
+            scan_rows_kept=ep.stats.scan_rows_kept - kept0,
             assignment_counts=assignment_counts,
             objective=objective)
         self.stats.recent.append(bs)

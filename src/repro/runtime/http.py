@@ -18,7 +18,11 @@ Routes (subset of the W3C SPARQL 1.1 Protocol):
   the write rides the same admission queue, serializing against the
   micro-batch window it shares (reads first, then the write commits), and
   returns a JSON ack (``inserted``/``deleted``/``new_terms``/...).
-- ``GET /stats`` — admission + engine counters as JSON.
+- ``GET /stats`` — admission + engine counters as JSON, among them the
+  engine's split of its time (``prescan_seconds``, ``join_seconds``) and
+  the scan masks' bytes fetched against the candidate rows they kept
+  (``scan_fetch_bytes``, ``scan_rows_kept``), for an operator without a
+  profiler.
 - ``GET /healthz`` — liveness probe.
 
 Results are W3C *SPARQL 1.1 Query Results JSON*: SELECT returns
@@ -320,7 +324,11 @@ class SparqlHttpServer:
             "engine": {"cache_hits": es.cache_hits,
                        "cache_misses": es.cache_misses,
                        "scans_executed": es.scans_executed,
-                       "scans_deduped": es.scans_deduped},
+                       "scans_deduped": es.scans_deduped,
+                       "prescan_seconds": round(es.prescan_seconds, 6),
+                       "join_seconds": round(es.join_seconds, 6),
+                       "scan_fetch_bytes": es.scan_fetch_bytes,
+                       "scan_rows_kept": es.scan_rows_kept},
             "last_batch": None if last is None else {
                 "seq": last.seq, "size": last.size,
                 "unique_texts": last.unique_texts,

@@ -66,6 +66,7 @@ import numpy as np
 
 from ..rdf.dictionary import Dictionary
 from ..rdf.graph import RDFStore
+from ..tracing import span
 from .matcher import MatchCapacityError, MatchResult
 from .query import (AndExpr, BoundExpr, Comparison, GroupPattern, NotExpr,
                     Operand, OrExpr, ParseError, ParsedQuery, QueryGraph,
@@ -909,6 +910,12 @@ def execute_any_batch(store: RDFStore, engine, queries: list,
     (:mod:`repro.edge.server`) and the serving pool runner
     (:func:`repro.runtime.serving.make_sparql_runner`) call.
     """
+    with span("algebra.evaluate"):
+        return _execute_any_batch(store, engine, queries, max_rows)
+
+
+def _execute_any_batch(store: RDFStore, engine, queries: list,
+                       max_rows: int | None) -> list:
     plans = [(i, q) for i, q in enumerate(queries) if is_algebra_plan(q)]
     plain = [(i, q) for i, q in enumerate(queries) if not is_algebra_plan(q)]
     leaves: list[BGPNode] = []

@@ -42,6 +42,7 @@ equality masks, so its raw expansion IS the surviving row count and
 
 from __future__ import annotations
 
+from ..tracing import span
 from .matcher import JoinStep, JoinStats, MatchCapacityError, MatchResult
 from .query import QueryGraph, TriplePattern
 
@@ -93,12 +94,19 @@ class DeviceBatch:
             stats: JoinStats | None = None) -> dict[tuple, MatchResult]:
         if not self._jobs:
             return {}
+        with span("device_join.run"):
+            return self._run(max_rows, stats)
+
+    def _run(self, max_rows: int,
+             stats: JoinStats | None) -> dict[tuple, MatchResult]:
         pend = [(ck, len(q.patterns),
                  self._exec(q, plan, max_rows, stats))
                 for ck, q, plan in self._jobs]
         # the ONE bulk transfer: every job's binding + edge columns at once
-        fetched = self._be._fetch([(cols, {k: e for k, (e, _) in edges.items()})
-                                   for _, _, (cols, edges) in pend])
+        with span("device_join.fetch"):
+            fetched = self._be._fetch(
+                [(cols, {k: e for k, (e, _) in edges.items()})
+                 for _, _, (cols, edges) in pend])
         out: dict[tuple, MatchResult] = {}
         for (ck, E, (_, edges)), (h_cols, h_edges) in zip(pend, fetched):
             R = len(next(iter(h_edges.values())))

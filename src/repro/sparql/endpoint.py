@@ -43,6 +43,7 @@ import numpy as np
 
 from ..rdf.dictionary import Dictionary
 from ..rdf.graph import RDFStore
+from ..tracing import span
 from .algebra import (AskNode, Node, SolutionTable, compile_query,
                       evaluate_many, explain_plan)
 from .engine import EngineStats, QueryEngine
@@ -126,8 +127,9 @@ class SparqlEndpoint:
             if plan is not None:
                 self._plans.move_to_end(key)
                 return plan
-        plan = compile_query(parse_query(text, self.dictionary),
-                             self.dictionary)
+        with span("endpoint.parse"):
+            plan = compile_query(parse_query(text, self.dictionary),
+                                 self.dictionary)
         with self._memo_lock:
             self._plans[key] = plan
             while len(self._plans) > self._plan_cache_size:
@@ -161,6 +163,10 @@ class SparqlEndpoint:
         key could honestly claim (regression-tested in
         ``tests/test_serving_http.py``).
         """
+        with span("endpoint.run"):
+            return self._run_memoized(texts)
+
+    def _run_memoized(self, texts: list[str]) -> list[SolutionTable]:
         v = self.store.version
         found: dict[str, SolutionTable] = {}
         todo: dict[str, Node] = {}

@@ -125,6 +125,7 @@ from typing import Callable
 import numpy as np
 
 from ..rdf.graph import RDFStore
+from ..tracing import install_gc_spans, span
 from .device_join import DeviceBatch, device_eligible
 from .matcher import (CandidateParts, JoinStats, MatchResult, _candidates,
                       match_bgp, plan_bgp)
@@ -280,6 +281,9 @@ class JaxBackend(MatcherBackend):
     device->host array materializations; ``scalar_syncs`` counts the O(1)
     control scalars (row counts) host-driven allocation needs — see the
     :mod:`~repro.sparql.device_join` docstring for the accounting contract.
+    ``scan_fetch_bytes`` counts the bytes of scan masks fetched and
+    ``scan_rows_kept`` the candidate rows that survive their unpack: their
+    ratio is what the host scan path moves per row it keeps.
     """
 
     name = "jax"
@@ -315,6 +319,8 @@ class JaxBackend(MatcherBackend):
         self.host_transfers = 0
         self.host_transfer_bytes = 0
         self.scalar_syncs = 0
+        self.scan_fetch_bytes = 0
+        self.scan_rows_kept = 0
         # staging LRU is shared across overlapped server batches
         self._stage_lock = threading.Lock()
 
@@ -332,6 +338,20 @@ class JaxBackend(MatcherBackend):
             self.host_transfers += 1
             self.host_transfer_bytes += nbytes
         return out
+
+    def _fetch_masks(self, masks: list) -> list:
+        """Fetch scan masks in one bulk transfer, counted in
+        ``scan_fetch_bytes``."""
+        nbytes = sum(int(m.nbytes) for m in masks)
+        with span("engine.scan_fetch", bytes=nbytes):
+            out = self._fetch(masks)
+        with self._stage_lock:
+            self.scan_fetch_bytes += nbytes
+        return out
+
+    def _count_kept(self, parts: list[np.ndarray]) -> None:
+        with self._stage_lock:
+            self.scan_rows_kept += sum(len(p) for p in parts)
 
     def _scalar(self, x) -> int:
         """Sync one O(1) control scalar off the device (counted separately
@@ -458,18 +478,21 @@ class JaxBackend(MatcherBackend):
         from ..kernels.triple_scan import triple_scan
         import jax.numpy as jnp
 
-        pat = jnp.asarray(self._pattern_vec(tp))
-        slots = self._store_slots(store)
-        scan_parts = self._scan_parts(store, tp)
-        masks = [triple_scan(self._columns(flat, min_slots=slots), pat,
-                             bt=self.bt, interpret=self.interpret)
-                 for flat, _off in scan_parts]
+        with span("engine.scan_launch"):
+            pat = jnp.asarray(self._pattern_vec(tp))
+            slots = self._store_slots(store)
+            scan_parts = self._scan_parts(store, tp)
+            masks = [triple_scan(self._columns(flat, min_slots=slots), pat,
+                                 bt=self.bt, interpret=self.interpret)
+                     for flat, _off in scan_parts]
+        fetched = self._fetch_masks(masks) if masks else []
         parts: list[np.ndarray] = []
-        for (flat, off), mask in zip(scan_parts,
-                                     self._fetch(masks) if masks else []):
-            tids = np.flatnonzero(mask).astype(np.int64) + off
-            # the repeated-variable filter distributes over partitions
-            parts.append(self._repeated_var_filter(store, tp, tids))
+        with span("engine.scan_unpack"):
+            for (flat, off), mask in zip(scan_parts, fetched):
+                tids = np.flatnonzero(mask).astype(np.int64) + off
+                # the repeated-variable filter distributes over partitions
+                parts.append(self._repeated_var_filter(store, tp, tids))
+        self._count_kept(parts)
         return CandidateParts(parts)
 
     def candidates(self, store: RDFStore, tp: TriplePattern) -> np.ndarray:
@@ -486,31 +509,35 @@ class JaxBackend(MatcherBackend):
         if not uniq:
             return {}
 
-        # group deduplicated scans by the flat store (shard) they touch;
-        # a monolithic store is a single group
-        groups: dict[int, tuple[object, int, list[tuple]]] = {}
-        for k, tp in uniq.items():
-            for flat, off in self._scan_parts(store, tp):
-                g = groups.get(id(flat))
-                if g is None:
-                    g = groups[id(flat)] = (flat, off, [])
-                g[2].append(k)
+        with span("engine.scan_launch"):
+            # group deduplicated scans by the flat store (shard) they
+            # touch; a monolithic store is a single group
+            groups: dict[int, tuple[object, int, list[tuple]]] = {}
+            for k, tp in uniq.items():
+                for flat, off in self._scan_parts(store, tp):
+                    g = groups.get(id(flat))
+                    if g is None:
+                        g = groups[id(flat)] = (flat, off, [])
+                    g[2].append(k)
 
-        slots = self._store_slots(store)
-        parts: dict[tuple, list[np.ndarray]] = {k: [] for k in uniq}
-        launches = []
-        for flat, off, keys in groups.values():     # one launch per group
-            pats = np.stack([self._pattern_vec(uniq[k]) for k in keys])
-            launches.append((off, keys, triple_scan_many(
-                self._columns(flat, min_slots=slots), jnp.asarray(pats),
-                bt=self.bt, interpret=self.interpret)))
+            slots = self._store_slots(store)
+            launches = []
+            for flat, off, keys in groups.values():  # one launch per group
+                pats = np.stack([self._pattern_vec(uniq[k]) for k in keys])
+                launches.append((off, keys, triple_scan_many(
+                    self._columns(flat, min_slots=slots), jnp.asarray(pats),
+                    bt=self.bt, interpret=self.interpret)))
         # ONE bulk transfer materializes every group's masks together
-        fetched = self._fetch([m for _, _, m in launches]) if launches else []
-        for (off, keys, _), masks in zip(launches, fetched):
-            for i, k in enumerate(keys):
-                tids = np.flatnonzero(masks[i]).astype(np.int64) + off
-                parts[k].append(
-                    self._repeated_var_filter(store, uniq[k], tids))
+        fetched = (self._fetch_masks([m for _, _, m in launches])
+                   if launches else [])
+        parts: dict[tuple, list[np.ndarray]] = {k: [] for k in uniq}
+        with span("engine.scan_unpack"):
+            for (off, keys, _), masks in zip(launches, fetched):
+                for i, k in enumerate(keys):
+                    tids = np.flatnonzero(masks[i]).astype(np.int64) + off
+                    parts[k].append(
+                        self._repeated_var_filter(store, uniq[k], tids))
+        self._count_kept([p for ps in parts.values() for p in ps])
         return {k: CandidateParts(parts[k]) for k in uniq}
 
 
@@ -588,7 +615,9 @@ class EngineStats:
     device-eligible, one more for the host path's fused prescan when the
     batch is mixed — while ``scalar_syncs`` counts the O(1)-byte row-count
     reads host-driven allocation needs (excluded from the one-transfer
-    contract; see :mod:`repro.sparql.device_join`).
+    contract; see :mod:`repro.sparql.device_join`). ``scan_fetch_bytes`` /
+    ``scan_rows_kept`` mirror the backend's scan-mask bytes fetched and
+    candidate rows kept the same way (see :class:`JaxBackend`).
     """
 
     queries: int = 0
@@ -616,6 +645,8 @@ class EngineStats:
     host_transfers: int = 0
     host_transfer_bytes: int = 0
     scalar_syncs: int = 0
+    scan_fetch_bytes: int = 0
+    scan_rows_kept: int = 0
 
     @property
     def scans_deduped(self) -> int:
@@ -672,6 +703,7 @@ class QueryEngine:
         # guards caches + stats when one engine serves overlapped server
         # batches from multiple threads; the matcher hot path runs unlocked
         self._lock = threading.RLock()
+        install_gc_spans()
 
     def cache_probe(self, store: RDFStore, q: QueryGraph) -> dict:
         """Non-mutating cache provenance for one BGP: would this query hit
@@ -866,6 +898,11 @@ class QueryEngine:
         result cache (within the batch and across calls, until the store
         version changes).
         """
+        with span("engine.execute_batch", queries=len(queries)):
+            return self._execute_batch(store, queries)
+
+    def _execute_batch(self, store: RDFStore,
+                       queries: list[QueryGraph]) -> list[MatchResult]:
         t0 = time.perf_counter()
         with self._lock:
             self.stats.batches += 1
@@ -973,11 +1010,12 @@ class QueryEngine:
                     # independent of this query's variable spelling
                     canon_q = self._canonical(q, canon_to_actual)
                     t_join = time.perf_counter()
-                    cached = match_bgp(store, canon_q,
-                                       max_rows=self.max_rows,
-                                       candidates=scan, plan=plans.get(i),
-                                       stats=join_stats,
-                                       shard_local=self.shard_local_joins)
+                    with span("engine.host_join"):
+                        cached = match_bgp(
+                            store, canon_q, max_rows=self.max_rows,
+                            candidates=scan, plan=plans.get(i),
+                            stats=join_stats,
+                            shard_local=self.shard_local_joins)
                     join_dt += time.perf_counter() - t_join
                 self._cache_put((store.version, ck), cached)
             out[i] = self._remap(cached, canon_to_actual)
@@ -991,5 +1029,7 @@ class QueryEngine:
                 self.stats.host_transfers = bk.host_transfers
                 self.stats.host_transfer_bytes = bk.host_transfer_bytes
                 self.stats.scalar_syncs = bk.scalar_syncs
+                self.stats.scan_fetch_bytes = bk.scan_fetch_bytes
+                self.stats.scan_rows_kept = bk.scan_rows_kept
             self.stats.exec_seconds += time.perf_counter() - t0
         return out

@@ -352,6 +352,15 @@ def test_http_concurrent_clients_one_batch(graph):
     assert stats["admission"]["batches"] <= 3
     assert stats["admission"]["max_coalesced"] >= len(QUERIES)
     assert stats["endpoint_memo"]["hits"] >= 1   # duplicate texts memo-hit
+    # the engine's split of its time and the scan path's counters, as the
+    # engine counted them (the numpy backend fetches no scan masks)
+    es = ep.stats
+    assert stats["engine"]["prescan_seconds"] == round(es.prescan_seconds, 6)
+    assert stats["engine"]["join_seconds"] == round(es.join_seconds, 6)
+    assert 0 < stats["engine"]["prescan_seconds"]
+    assert 0 < stats["engine"]["join_seconds"]
+    assert stats["engine"]["scan_fetch_bytes"] == es.scan_fetch_bytes == 0
+    assert stats["engine"]["scan_rows_kept"] == es.scan_rows_kept == 0
 
 
 # ---------------------------------------------------------------------------
