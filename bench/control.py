@@ -29,7 +29,7 @@ HERE = Path(__file__).resolve().parent
 sys.path[:0] = [str(HERE.parent)]
 
 from bench import run  # noqa: E402
-from bench.lib import loop, traffic, watdiv  # noqa: E402
+from bench.lib import loop, traffic  # noqa: E402
 from bench.lib.reference import Reference, RowLimit  # noqa: E402
 
 
@@ -46,8 +46,9 @@ class Table:
 
 def control_readings(cell: dict, seed: int, n: int,
                      scale: float | None = None, id_dtype=np.int16) -> dict:
-    graph = watdiv.generate(scale or cell["config"]["data"]["scale"], seed)
-    requests = traffic.Requests(cell["traffic"], graph, seed)
+    dataset = cell["dataset"]
+    graph = dataset.generate(scale or cell["config"]["data"]["scale"], seed)
+    requests = traffic.Requests(cell["traffic"], dataset, graph, seed)
     window = requests.window()
     control = Reference(graph.s, graph.p, graph.o, id_dtype=id_dtype,
                         max_rows=CONTROL_MAX_ROWS)

@@ -6,7 +6,8 @@ from __future__ import annotations
 from .base import Deployment, dictionary_of, timed
 
 
-def build(config: dict, graph, seed: int, times: dict) -> Deployment:
+def build(config: dict, dataset, graph, seed: int,
+          times: dict) -> Deployment:
     from repro.rdf.sharding import ShardedTripleStore
     from repro.sparql.endpoint import SparqlEndpoint
     from repro.sparql.engine import JaxBackend, QueryEngine

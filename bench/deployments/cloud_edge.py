@@ -4,7 +4,6 @@ history, every batch scheduled across them by branch and bound."""
 
 from __future__ import annotations
 
-from ..lib import watdiv
 from .base import Deployment, dictionary_of, timed
 
 
@@ -14,7 +13,8 @@ class CloudEdge(Deployment):
         super().__init__(endpoint)
 
 
-def build(config: dict, graph, seed: int, times: dict) -> CloudEdge:
+def build(config: dict, dataset, graph, seed: int,
+          times: dict) -> CloudEdge:
     from repro.core.cost import SystemParams
     from repro.edge.system import EdgeCloudSystem
     from repro.rdf.sharding import ShardedTripleStore
@@ -27,9 +27,9 @@ def build(config: dict, graph, seed: int, times: dict) -> CloudEdge:
         graph.s, graph.p, graph.o, len(graph.entities),
         len(graph.predicates), num_shards=dep["shards"]))
     hist = dep["history"]
-    history = [watdiv.workload_sparql(graph, hist["queries_per_user"],
-                                      seed=[seed, 1000 + n],
-                                      templates=hist["templates"])
+    history = [dataset.workload_sparql(graph, hist["queries_per_user"],
+                                       seed=[seed, 1000 + n],
+                                       templates=hist["templates"])
                for n in range(dep["users"])]
     params = SystemParams.synthetic(n_users=dep["users"],
                                     n_edges=dep["edges"],

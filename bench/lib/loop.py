@@ -30,8 +30,8 @@ class LoopResult:
     start: float
     end: float
     outcomes: list[Outcome] = field(default_factory=list)
-    #: whether the list ran out of requests
-    exhausted: bool = False
+    #: why the list ran out of requests, if it did
+    exhausted: str | None = None
 
     @property
     def seconds(self) -> float:
@@ -52,8 +52,8 @@ def run(queue, requests, clients: int,
                     return
                 try:
                     req = requests[nxt[0]]
-                except IndexError:
-                    res.exhausted = True
+                except IndexError as err:
+                    res.exhausted = str(err) or "the request list ran out"
                     return
                 nxt[0] += 1
             sent = time.perf_counter()

@@ -8,7 +8,8 @@ configuration file (``bench/configs/``) says what to deploy, its traffic
 file (``bench/traffic/<traffic>.json``) what to send, and each of its
 metrics is read by ``bench/metrics/<metric>.py``. A run:
 
-1. generates the WatDiv-like graph from ``--seed`` (``bench/lib/watdiv``)
+1. generates the configuration's data set from ``--seed``, through the
+   generator module its ``data.module`` names (``bench/lib/datasets.py``),
    and draws the run's requests from the traffic file;
 2. builds the deployment (``bench/deployments/<kind>.py``);
 3. warms up: sends warm-up's own requests (``warmup`` in the traffic
@@ -82,7 +83,11 @@ def load_spec(parked: bool = False) -> dict:
 
 def load_cell(name: str, spec: dict | None = None) -> dict:
     """The cell ``name`` of ``spec`` (``load_spec()`` by default) with its
-    configuration, traffic and metrics."""
+    configuration, its data set's generator module, traffic and metrics;
+    raises ``ValueError`` where the configuration names no data set or the
+    traffic cannot run on it."""
+    from bench.lib import datasets, traffic as traffic_lib
+
     spec = spec or load_spec()
     cells = {w["name"]: w for w in spec["workloads"]}
     if name not in cells:
@@ -92,6 +97,8 @@ def load_cell(name: str, spec: dict | None = None) -> dict:
     config = json.loads((ROOT / cfg_entry["file"]).read_text())
     traffic = json.loads(
         (HERE / "traffic" / f"{cell['traffic']}.json").read_text())
+    dataset = datasets.load(config)
+    traffic_lib.check(traffic, dataset)
 
     def in_cell(m):
         return name in m.get("workloads", [name])
@@ -100,8 +107,8 @@ def load_cell(name: str, spec: dict | None = None) -> dict:
     names = {m["name"] for m in e2e}
     per_layer = [m for m in spec["per_layer"]
                  if in_cell(m) and m["moves"] in names]
-    return {"cell": cell, "config": config, "traffic": traffic,
-            "end_to_end": e2e, "per_layer": per_layer}
+    return {"cell": cell, "config": config, "dataset": dataset,
+            "traffic": traffic, "end_to_end": e2e, "per_layer": per_layer}
 
 
 def reader(metric: str):
@@ -137,20 +144,21 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
     metric readers take, with the check's numbers under ``check``."""
     import jax
 
-    from bench.lib import loop, traffic, watdiv
+    from bench.lib import loop, traffic
     from bench.lib.compiles import CompileCounter
 
     counter = CompileCounter()
-    config, mix = cell["config"], cell["traffic"]
+    config, dataset, mix = cell["config"], cell["dataset"], cell["traffic"]
     if scale is not None:
         mix = dict(mix, warmup=min(mix["warmup"], REHEARSAL_WARMUP))
     times: dict[str, float] = {}
     t0 = time.perf_counter()
-    graph = watdiv.generate(scale or config["data"]["scale"], seed)
+    graph = dataset.generate(scale or config["data"]["scale"], seed)
     times["generate"] = time.perf_counter() - t0
-    requests = traffic.Requests(mix, graph, seed)
+    requests = traffic.Requests(mix, dataset, graph, seed)
     kind = config["deployment"]["kind"]
-    dep = deployment_module(kind).build(config, graph, seed, times)
+    dep = deployment_module(kind).build(config, dataset, graph, seed,
+                                        times)
 
     clients = mix["clients"]
     c0, h0, s0 = counter.compiles, counter.cache_hits, counter.seconds
@@ -196,8 +204,8 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
     after = stats_snapshot(dep.engine)
     if res.exhausted:
         raise BenchError(f"the window ran out of requests after "
-                         f"{len(res.outcomes)}, before its end: raise "
-                         f"'requests' in the traffic file")
+                         f"{len(res.outcomes)}, before its end: "
+                         f"{res.exhausted}")
 
     dev = jax.devices()[0]
     mem = dev.memory_stats() or {}
@@ -294,14 +302,14 @@ def main(argv: list[str] | None = None) -> int:
                          "scale, then exit non-zero")
     args = ap.parse_args(argv)
 
-    # a rehearsal may also run a parked cell
-    cell = load_cell(args.workload,
-                     load_spec(parked=args.rehearse_scale is not None))
     if args.rehearse_scale is None:
         os.environ["JAX_COMPILATION_CACHE_DIR"] = str(CACHE_DIR)
     # the TPU runtime logs to a fixed directory under /tmp unless told not to
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     sys.path[:0] = [str(ROOT / "src"), str(ROOT)]
+    # a rehearsal may also run a parked cell
+    cell = load_cell(args.workload,
+                     load_spec(parked=args.rehearse_scale is not None))
     import jax
 
     from repro.kernels import use_compile_cache
